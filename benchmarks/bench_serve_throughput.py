@@ -188,7 +188,9 @@ print(json.dumps(out))
 
 def _tp_workload(smoke):
     """tp=1 vs tp=2 paged decode on forced host devices (subprocess: the
-    bench process itself keeps exactly one device)."""
+    bench process itself keeps exactly one device). The child is pinned to
+    the CPU, so this never takes a chip, and its numbers are CPU numbers
+    on every host (``"platform": "cpu"``)."""
     spec = dict(tps=[1, 2], n_req=8 if smoke else 16,
                 max_new=8 if smoke else 16, max_slots=8, max_len=256)
     env = {**os.environ,
@@ -204,6 +206,7 @@ def _tp_workload(smoke):
     identical = out["1"]["tokens"] == out["2"]["tokens"]
     assert identical, "tp=2 greedy decode diverged from tp=1"
     return {
+        "platform": "cpu",
         "tps": spec["tps"],
         "tok_per_s": {tp: out[tp]["tok_per_s"] for tp in out},
         "kv_bytes_per_device": {tp: out[tp]["kv_bytes_per_device"]
@@ -405,6 +408,7 @@ def run():
     tp = _tp_workload(smoke)
     kv1, kv2 = (tp["kv_bytes_per_device"][k] for k in ("1", "2"))
     emit("serve_tp", 0.0,
+         f"platform={tp['platform']}_forced_host_devices_"
          f"tp2_tok/s={tp['tok_per_s']['2']:.1f}_"
          f"tp1_tok/s={tp['tok_per_s']['1']:.1f}_"
          f"kv/dev_{kv1/max(kv2,1):.1f}x_smaller_"

@@ -21,6 +21,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from benchmarks import (bench_compute_breakdown, bench_end2end,
                             bench_kernel_complexity, bench_kernels,

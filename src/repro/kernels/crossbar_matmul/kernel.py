@@ -30,8 +30,9 @@ DEFAULT_BLOCK_M = 256
 CROSSBAR = 128  # ReRAM crossbar size == MXU tile == quantization block
 
 
-def _kernel_int8(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k):
+def _kernel_int8(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k, n_n):
     k = pl.program_id(2)
+    scale = scale_ref[k * n_n + pl.program_id(1)]
 
     @pl.when(k == 0)
     def _init():
@@ -41,15 +42,16 @@ def _kernel_int8(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k):
     w = codes_ref[...].astype(jnp.float32)        # (128, bn) int8 codes
     partial = jnp.dot(x, w, preferred_element_type=jnp.float32)
     # post-MVM dequantization: one scale per 128x128 crossbar
-    acc_ref[...] += partial * scale_ref[0, 0]
+    acc_ref[...] += partial * scale
 
     @pl.when(k == n_k - 1)
     def _done():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _kernel_int4(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k):
+def _kernel_int4(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k, n_n):
     k = pl.program_id(2)
+    scale = scale_ref[k * n_n + pl.program_id(1)]
 
     @pl.when(k == 0)
     def _init():
@@ -65,7 +67,7 @@ def _kernel_int4(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k):
     # unpack interleaved along K: rows (2i, 2i+1) <- (lo_i, hi_i)
     w = jnp.stack([lo, hi], axis=1).reshape(CROSSBAR, -1).astype(jnp.float32)
     partial = jnp.dot(x, w, preferred_element_type=jnp.float32)
-    acc_ref[...] += partial * scale_ref[0, 0]
+    acc_ref[...] += partial * scale
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -76,7 +78,7 @@ def _kernel_int4(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *, n_k):
                                              "interpret", "out_dtype"))
 def crossbar_matmul(x, codes, scales, *, bits: int = 8,
                     block_m: int = DEFAULT_BLOCK_M, block_n: int = CROSSBAR,
-                    interpret: bool = True, out_dtype=None):
+                    interpret: bool = False, out_dtype=None):
     """x (M, K) @ dequant(codes, scales) -> (M, N).
 
     codes: int8 (K, N) for 8-bit, uint8 (K//2, N) packed for 4-bit.
@@ -87,14 +89,14 @@ def crossbar_matmul(x, codes, scales, *, bits: int = 8,
     out_dtype = out_dtype or x.dtype
     assert M % block_m == 0 and N % block_n == 0 and K % CROSSBAR == 0
     assert block_n == CROSSBAR, "one scale per crossbar: bn == 128"
-    n_k = K // CROSSBAR
-    grid = (M // block_m, N // block_n, n_k)
+    n_k, n_n = K // CROSSBAR, N // block_n
+    grid = (M // block_m, n_n, n_k)
 
     if bits == 8:
-        kern = functools.partial(_kernel_int8, n_k=n_k)
+        kern = functools.partial(_kernel_int8, n_k=n_k, n_n=n_n)
         codes_spec = pl.BlockSpec((CROSSBAR, block_n), lambda i, j, k: (k, j))
     elif bits == 4:
-        kern = functools.partial(_kernel_int4, n_k=n_k)
+        kern = functools.partial(_kernel_int4, n_k=n_k, n_n=n_n)
         codes_spec = pl.BlockSpec((CROSSBAR // 2, block_n), lambda i, j, k: (k, j))
     else:
         raise ValueError(bits)
@@ -105,10 +107,12 @@ def crossbar_matmul(x, codes, scales, *, bits: int = 8,
         in_specs=[
             pl.BlockSpec((block_m, CROSSBAR), lambda i, j, k: (i, k)),
             codes_spec,
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j)),
+            # every crossbar scale, flat, in scalar memory: a (1, 1) VMEM
+            # block of the (K/128, N/128) table is not a legal TPU tile
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x, codes, scales)
+    )(x, codes, scales.reshape(-1))

@@ -1,9 +1,9 @@
 """jit'd public wrapper: QuantizedTensor in, padding/tiling handled here.
 
-On TPU (``interpret=False``) this is the STATIC-engine execution path for
-every frozen-weight matmul; on CPU it runs the same kernel body in
-interpret mode (tests) while the model's XLA fallback path is used for
-large lowering."""
+The default (``interpret=False``) compiles the Mosaic kernel, which only a
+TPU runs; CPU tests pass ``interpret=True`` to run the same kernel body in
+the Pallas interpreter. No model path calls this yet: the model's
+frozen-weight matmuls dequantize in XLA (``core.hetero.static_matmul``)."""
 from __future__ import annotations
 
 import jax
@@ -15,7 +15,7 @@ from repro.kernels.crossbar_matmul.kernel import (CROSSBAR, DEFAULT_BLOCK_M,
 
 
 def crossbar_matmul(x, qt: QuantizedTensor, *, block_m: int = DEFAULT_BLOCK_M,
-                    interpret: bool = True, out_dtype=None):
+                    interpret: bool = False, out_dtype=None):
     """x (..., K) @ qt (K, N) -> (..., N) via the Pallas crossbar kernel."""
     assert qt.ndim == 2, "2D weights (batched experts loop in the caller)"
     K, N = qt.orig_shape

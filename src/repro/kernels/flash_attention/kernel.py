@@ -36,11 +36,11 @@ def _attn_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, out_ref,
                             preferred_element_type=jnp.float32)  # (bq, bk)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    qp = qpos_ref[0]                              # (bq,)
-    kp = kpos_ref[0]                              # (bk,)
-    mask = (kp[None, :] <= qp[:, None]) & (kp[None, :] >= 0)
+    qp = qpos_ref[0]                              # (bq, 1)
+    kp = kpos_ref[0]                              # (1, bk)
+    mask = (kp <= qp) & (kp >= 0)
     if window is not None:
-        mask &= (qp[:, None] - kp[None, :]) < window
+        mask &= (qp - kp) < window
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -64,10 +64,14 @@ def _attn_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, out_ref,
                                              "block_kv", "interpret"))
 def flash_attention_kernel(q, k, v, q_pos, kv_pos, *, window=None,
                            softcap=None, block_q=128, block_kv=128,
-                           interpret=True):
+                           interpret=False):
     """q (BH, T, D); k/v (BHkv, S, D); q_pos (BH, T); kv_pos (BHkv, S).
     BH == B*Hq, BHkv == B*Hkv with Hq grouped per kv head (GQA): program
-    (bh, ...) reads kv block bh // group."""
+    (bh, ...) reads kv block bh // group.
+
+    Positions enter as a (BH, T, 1) column and a (BHkv, 1, S) row: the TPU
+    lowering needs the last two block dims (8, 128)-aligned or full, and
+    the column/row layout also lets the mask broadcast without a relayout."""
     BH, T, D = q.shape
     BHkv, S, _ = k.shape
     group = BH // BHkv
@@ -82,8 +86,8 @@ def flash_attention_kernel(q, k, v, q_pos, kv_pos, *, window=None,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, i, j, kb: (b, i)),
-            pl.BlockSpec((1, block_kv), lambda b, i, j, kb: (b // group, kb)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j, kb: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, i, j, kb: (b // group, 0, kb)),
             pl.BlockSpec((1, block_q, D), lambda b, i, j, kb: (b, i, 0)),
             pl.BlockSpec((1, block_kv, D), lambda b, i, j, kb: (b // group, kb, 0)),
             pl.BlockSpec((1, block_kv, D), lambda b, i, j, kb: (b // group, kb, 0)),
@@ -96,4 +100,4 @@ def flash_attention_kernel(q, k, v, q_pos, kv_pos, *, window=None,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_pos, kv_pos, q, k, v)
+    )(q_pos[:, :, None], kv_pos[:, None, :], q, k, v)

@@ -8,7 +8,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
 def flash_attention(q, k, v, q_pos, kv_pos, *, window=None, softcap=None,
-                    block_q=128, block_kv=128, interpret=True):
+                    block_q=128, block_kv=128, interpret=False):
     """q (B, T, Hq, D); k/v (B, S, Hkv, D); positions (B, T)/(B, S)."""
     B, T, Hq, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]

@@ -27,16 +27,30 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     def _init():
         s_ref[...] = s0_ref[0]
 
+    N = s_ref.shape[0]
+    # row -> column through the identity (a lane reduction): every vector
+    # stays 2D, so the TPU lowering needs no relayout of 1D values
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)
+           ).astype(jnp.float32)
+
+    def col(row):                                   # (1, N) -> (N, 1)
+        return jnp.sum(eye * row, axis=1, keepdims=True)
+
+    u = u_ref[0]                                    # (1, N)
+
     def step(i, _):
-        rt = r_ref[0, i]                        # (N,)
-        kt = k_ref[0, i]
-        vt = v_ref[0, i]
-        wt = w_ref[0, i]
-        s = s_ref[...]                          # (N, N)
-        kv = kt[:, None] * vt[None, :]
-        y = jnp.sum(rt[:, None] * (s + u_ref[0][:, None] * kv), axis=0)
-        y_ref[0, i] = y.astype(y_ref.dtype)
-        s_ref[...] = wt[:, None] * s + kv
+        rt = r_ref[0, pl.ds(i, 1), :]               # (1, N)
+        kt = k_ref[0, pl.ds(i, 1), :]
+        vt = v_ref[0, pl.ds(i, 1), :]
+        wt = w_ref[0, pl.ds(i, 1), :]
+        s = s_ref[...]                              # (N, N)
+        kv = col(kt) * vt                           # k_t v_t^T
+        # r_t (S + u ⊙ k_t v_t^T) = r_t S + (Σ r u k) v_t
+        y = (jnp.sum(col(rt) * s, axis=0, keepdims=True)
+             + jnp.sum(rt * u * kt, axis=1, keepdims=True) * vt)
+        y_ref[0, pl.ds(i, 1), :] = y.astype(y_ref.dtype)
+        s_ref[...] = col(wt) * s + kv
         return ()
 
     jax.lax.fori_loop(0, block_t, step, ())
@@ -47,7 +61,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def rwkv6_wkv_kernel(r, k, v, w, u, s0, *, block_t=64, interpret=True):
+def rwkv6_wkv_kernel(r, k, v, w, u, s0, *, block_t=64, interpret=False):
     """r/k/v/w (BH, T, N) f32; u (BH, N); s0 (BH, N, N).
     Returns y (BH, T, N), s_final (BH, N, N)."""
     BH, T, N = r.shape
@@ -61,7 +75,9 @@ def rwkv6_wkv_kernel(r, k, v, w, u, s0, *, block_t=64, interpret=True):
         grid=grid,
         in_specs=[
             seq_spec, seq_spec, seq_spec, seq_spec,
-            pl.BlockSpec((1, N), lambda b, t: (b, 0)),
+            # (BH, 1, N): a full-extent (1, N) tile per head (the TPU
+            # lowering refuses a (1, N) block of a (BH, N) array)
+            pl.BlockSpec((1, 1, N), lambda b, t: (b, 0, 0)),
             pl.BlockSpec((1, N, N), lambda b, t: (b, 0, 0)),
         ],
         out_specs=[
@@ -74,4 +90,4 @@ def rwkv6_wkv_kernel(r, k, v, w, u, s0, *, block_t=64, interpret=True):
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u[:, None, :], s0)

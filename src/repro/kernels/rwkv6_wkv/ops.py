@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from repro.kernels.rwkv6_wkv.kernel import rwkv6_wkv_kernel
 
 
-def rwkv6_wkv(r, k, v, w, u, s0, *, block_t=64, interpret=True):
+def rwkv6_wkv(r, k, v, w, u, s0, *, block_t=64, interpret=False):
     """r/k/v/w (B, T, H, N) f32; u (H, N); s0 (B, H, N, N)."""
     B, T, H, N = r.shape
     bt = min(block_t, T)

@@ -54,15 +54,13 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     try:
         cell = build_cell(cfg, shape, mesh, quant_cfg=parse_quant(quant_tag),
                           microbatches=microbatches, attn_impl=attn_impl)
-        with mesh:  # Mesh context works on jax<0.5 (no jax.set_mesh there)
+        with jax.set_mesh(mesh):
             lowered = jax.jit(cell.step).lower(*cell.args)
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax<0.5 returns [dict]
-            ca = ca[0] if ca else {}
         txt = compiled.as_text()
         hlo_raw = HloModule(txt)
         cost_raw = hlo_raw.entry_cost()

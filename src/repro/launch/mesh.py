@@ -10,10 +10,8 @@ import jax
 import numpy as np
 
 
-def _mesh_kwargs(n_axes: int):
-    # jax < 0.5 has no AxisType; Auto is the default behaviour there anyway
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+def _auto(n_axes: int):
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,11 +19,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     chips (pod, data, model); the pod axis is a second (DCN) data axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model: Optional[int] = None):
@@ -46,5 +44,4 @@ def make_tp_mesh(tp: int):
     if tp < 1 or tp > n:
         raise ValueError(f"tp={tp} needs 1..{n} local devices")
     devs = np.asarray(jax.devices()[:tp], dtype=object).reshape(1, tp)
-    return jax.sharding.Mesh(devs, ("data", "model"),
-                             **_mesh_kwargs(2))
+    return jax.sharding.Mesh(devs, ("data", "model"), axis_types=_auto(2))

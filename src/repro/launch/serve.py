@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.configs import get_config, reduce_config
 from repro.core import lora as lora_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import init_params
 from repro.serve.api import ParallelConfig, Request, make_engine
 
@@ -80,6 +81,7 @@ def main(argv=None):
     ap.add_argument("--prefix-cache-path", default=None,
                     help="persist/restore the prefix index at this .npz path")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

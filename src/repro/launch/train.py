@@ -20,6 +20,7 @@ from repro.configs.base import QuantConfig
 from repro.core import quant as quant_lib
 from repro.core.noise import NoiseConfig
 from repro.data.pipeline import make_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import ExecConfig, init_params
 from repro.optim.adamw import AdamWConfig, warmup_cosine
 from repro.train.steps import TrainHParams
@@ -43,6 +44,7 @@ def main(argv=None):
     ap.add_argument("--data", default=None, help="memmap token file")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -10,7 +10,8 @@ Three interchangeable implementations of the fused score+softmax+V step
                   Q block can see (FLOPs scale with window, not seq —
                   8x reduction at 32k/w4096), then runs ``blocked`` inside.
   * pallas      — TPU kernel (repro.kernels.flash_attention), selected via
-                  ``impl='pallas'``; validated in interpret mode.
+                  ``impl='pallas'``; checked against ``ref`` in interpret
+                  mode and compiled for v5e in tests/test_tpu_compile.py.
 
 Supports GQA (any q/kv head ratio), causal masking via explicit position
 arrays (required under sequence-parallel Q sharding), sliding windows,

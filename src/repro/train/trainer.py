@@ -121,7 +121,11 @@ class Trainer:
 
     def run_with_restarts(self) -> List[Dict[str, float]]:
         """Fault-tolerant driver: on any step failure, restore the last
-        checkpoint and continue (bounded by the restart policy)."""
+        checkpoint and continue (bounded by the restart policy). With no
+        checkpoint directory there is nothing to restore, so the first
+        failure propagates."""
+        if not self.tc.ckpt_dir:
+            return self.run()
         while True:
             try:
                 return self.run()

@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles. The kernels run
+in Pallas interpret mode here (``interpret=True``): the CPU cannot run a
+Mosaic kernel; tests/test_tpu_compile.py compiles them for the TPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +26,8 @@ def test_crossbar_matmul_sweep(bits, mkn, dtype):
     w = jax.random.normal(kw, (K, N), jnp.float32) * 0.1
     x = (jax.random.normal(kx, (M, K), jnp.float32)).astype(dtype)
     qt = quantize(w, bits)
-    y = cb_ops.crossbar_matmul(x, qt, block_m=32, out_dtype=jnp.float32)
+    y = cb_ops.crossbar_matmul(x, qt, block_m=32, out_dtype=jnp.float32,
+                               interpret=True)
     yr = cb_ref.crossbar_matmul_ref(x.astype(jnp.float32), qt,
                                     out_dtype=jnp.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
@@ -36,7 +39,7 @@ def test_crossbar_batched_lead_dims():
     w = jax.random.normal(KEY, (256, 128)) * 0.1
     x = jax.random.normal(jax.random.fold_in(KEY, 1), (2, 5, 256))
     qt = quantize(w, 8)
-    y = cb_ops.crossbar_matmul(x, qt, block_m=32)
+    y = cb_ops.crossbar_matmul(x, qt, block_m=32, interpret=True)
     assert y.shape == (2, 5, 128)
     yr = cb_ref.crossbar_matmul_ref(x, qt)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=1e-4,
@@ -58,7 +61,8 @@ def test_flash_attention_sweep(B, T, S, Hq, Hkv, D, window, softcap):
     kpos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     o_ref = ref_attention(q, k, v, qpos, kpos, window=window, softcap=softcap)
     o_ker = fa_ops.flash_attention(q, k, v, qpos, kpos, window=window,
-                                   softcap=softcap, block_q=16, block_kv=16)
+                                   softcap=softcap, block_q=16, block_kv=16,
+                                   interpret=True)
     np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -72,11 +76,13 @@ def test_flash_attention_invalid_slots_masked():
     v = jax.random.normal(ks[2], (B, S, H, D))
     qpos = jnp.broadcast_to(jnp.arange(T)[None] + 100, (B, T))
     kpos = jnp.where(jnp.arange(S) < 20, jnp.arange(S) + 90, -1)[None]
-    o1 = fa_ops.flash_attention(q, k, v, qpos, kpos, block_q=8, block_kv=8)
+    o1 = fa_ops.flash_attention(q, k, v, qpos, kpos, block_q=8, block_kv=8,
+                                interpret=True)
     # corrupt the invalid region: output must not change
     k2 = k.at[:, 20:].set(999.0)
     v2 = v.at[:, 20:].set(-999.0)
-    o2 = fa_ops.flash_attention(q, k2, v2, qpos, kpos, block_q=8, block_kv=8)
+    o2 = fa_ops.flash_attention(q, k2, v2, qpos, kpos, block_q=8, block_kv=8,
+                                interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
 
 
@@ -89,7 +95,8 @@ def test_rwkv6_wkv_sweep(B, T, H, N, bt):
     u = jax.random.normal(ks[4], (H, N)) * 0.3
     s0 = jax.random.normal(jax.random.fold_in(KEY, 9), (B, H, N, N)) * 0.1
     y_ref, s_ref = wkv_scan(r, k, v, w, u, s0)
-    y_k, s_k = wkv_ops.rwkv6_wkv(r, k, v, w, u, s0, block_t=bt)
+    y_k, s_k = wkv_ops.rwkv6_wkv(r, k, v, w, u, s0, block_t=bt,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref), rtol=1e-5,
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_ref), rtol=1e-5,

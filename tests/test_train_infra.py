@@ -1,5 +1,6 @@
 """Optimizer, grad accumulation, data determinism, checkpoint, trainer
-fault-tolerance."""
+fault-tolerance, the launchers' compile cache."""
+import pathlib
 import tempfile
 
 import jax
@@ -133,7 +134,6 @@ def test_checkpoint_roundtrip_and_gc():
         np.testing.assert_array_equal(np.asarray(back["a"]),
                                       np.asarray(tree["a"]))
         # gc kept only 2
-        import pathlib
         assert len(list(pathlib.Path(d).glob("step_*"))) == 2
 
 
@@ -171,6 +171,23 @@ def test_trainer_restart_after_injected_failure():
         assert len(log) >= 20
 
 
+def test_trainer_without_checkpoints_raises_first_failure():
+    """No checkpoint directory, nothing to restore: a failed step fails the
+    run instead of restarting it from scratch."""
+    cfg = reduce_config(get_config("llama3.2-1b"))
+    ds = SyntheticLM(cfg.vocab_size, seed=3)
+    tc = TrainerConfig(seq_len=32, global_batch=4, steps=3, log_every=100)
+
+    def hook(step):
+        if step == 1:
+            raise RuntimeError("injected failure")
+
+    tr = Trainer(cfg, tc, ds, step_hook=hook)
+    with pytest.raises(RuntimeError, match="injected failure"):
+        tr.run_with_restarts()
+    assert tr.fault.restarts == 0 and tr.step == 1
+
+
 def test_straggler_monitor_and_spare_swap():
     from repro.dist.fault import FaultCoordinator, RestartPolicy
     fc = FaultCoordinator(RestartPolicy(straggler_patience=2))
@@ -201,3 +218,28 @@ def test_elastic_resume_changes_nothing_numerically():
         l1 = [r["loss"] for r in log1 if r["step"] > 5]
         l2 = [r["loss"] for r in log2]
         np.testing.assert_allclose(l1, l2, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# launchers: where the persistent compilation cache lives
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    otherwise the cache is the checkout's fixed ``.jax_cache``."""
+    from repro.launch.compile_cache import use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(pathlib.Path(__file__).resolve().parents[1]
+                       / ".jax_cache")
+            assert use_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
