@@ -1,0 +1,8 @@
+"""Device idle share of the traced training segment: 1 - busy / window, %."""
+
+
+def read(data):
+    tr = data.get("trace")
+    if data["kind"] != "train" or tr is None or tr.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - tr.busy_s / tr.window_s)
