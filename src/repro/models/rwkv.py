@@ -20,6 +20,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import hetero
 from repro.core.noise import NoiseConfig
@@ -158,80 +159,82 @@ def apply_rwkv_block(
     scale = lora_scale(cfg)
 
     # ---------------- time mix ----------------
-    xn = layers.layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-    xx = _token_shift(xn, cache["shift_t"] if cache is not None else None)
-    diff = xx - xn
-    # dynamic token-shift mixing (the "ddd" lora)
-    xmix = xn + diff * tm["mu_x"]
-    ddd = jnp.tanh(hetero.static_matmul(xmix, tm["w_mix_a"]))
-    ddd = ddd.reshape(B, T, 5, rc.mix_lora)
-    dyn = hetero.dynamic_einsum("btfr,frd->btfd", ddd,
-                                tm["w_mix_b"].astype(x.dtype))
-    mixed = {}
-    for i, name in enumerate(MIX_NAMES):
-        mixed[name] = xn + diff * (tm["mu"][i] + dyn[:, :, i, :])
+    with jax.named_scope(obs.RECURRENT):
+        xn = layers.layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
+        xx = _token_shift(xn, cache["shift_t"] if cache is not None else None)
+        diff = xx - xn
+        # dynamic token-shift mixing (the "ddd" lora)
+        xmix = xn + diff * tm["mu_x"]
+        ddd = jnp.tanh(hetero.static_matmul(xmix, tm["w_mix_a"]))
+        ddd = ddd.reshape(B, T, 5, rc.mix_lora)
+        dyn = hetero.dynamic_einsum("btfr,frd->btfd", ddd,
+                                    tm["w_mix_b"].astype(x.dtype))
+        mixed = {}
+        for i, name in enumerate(MIX_NAMES):
+            mixed[name] = xn + diff * (tm["mu"][i] + dyn[:, :, i, :])
 
-    def proj(name, target):
-        y = hetero.static_matmul(mixed[name], tm[f"{name}_proj"],
-                                 noise=noise, rng=rng)
-        if lora is not None and target in lora:
-            y = y + lora_delta(mixed[name], lora[target], scale, adapter_idx)
-        return y
+        def proj(name, target):
+            y = hetero.static_matmul(mixed[name], tm[f"{name}_proj"],
+                                     noise=noise, rng=rng)
+            if lora is not None and target in lora:
+                y = y + lora_delta(mixed[name], lora[target], scale, adapter_idx)
+            return y
 
-    r = proj("r", "wq").reshape(B, T, H, N).astype(jnp.float32)
-    k = proj("k", "wk").reshape(B, T, H, N).astype(jnp.float32)
-    v = proj("v", "wv").reshape(B, T, H, N).astype(jnp.float32)
-    g = jax.nn.silu(hetero.static_matmul(mixed["g"], tm["g_proj"],
-                                         noise=noise, rng=rng))
+        r = proj("r", "wq").reshape(B, T, H, N).astype(jnp.float32)
+        k = proj("k", "wk").reshape(B, T, H, N).astype(jnp.float32)
+        v = proj("v", "wv").reshape(B, T, H, N).astype(jnp.float32)
+        g = jax.nn.silu(hetero.static_matmul(mixed["g"], tm["g_proj"],
+                                             noise=noise, rng=rng))
 
-    # data-dependent decay w_t in (0, 1)
-    w_raw = tm["w_base"] + hetero.dynamic_matmul(
-        jnp.tanh(hetero.static_matmul(mixed["w"], tm["w_lora_a"])),
-        tm["w_lora_b"].astype(x.dtype)).astype(jnp.float32)
-    w = jnp.exp(-jnp.exp(w_raw)).reshape(B, T, H, N)
-    hetero.record_nonlinear(w.size * 2)
+        # data-dependent decay w_t in (0, 1)
+        w_raw = tm["w_base"] + hetero.dynamic_matmul(
+            jnp.tanh(hetero.static_matmul(mixed["w"], tm["w_lora_a"])),
+            tm["w_lora_b"].astype(x.dtype)).astype(jnp.float32)
+        w = jnp.exp(-jnp.exp(w_raw)).reshape(B, T, H, N)
+        hetero.record_nonlinear(w.size * 2)
 
-    if chunk_lens is not None:
-        # padded steps: k=0, w=1 -> wkv state passes through unchanged
-        valid = (jnp.arange(T)[None, :] < chunk_lens[:, None])[..., None, None]
-        k = jnp.where(valid, k, 0.0)
-        w = jnp.where(valid, w, 1.0)
+        if chunk_lens is not None:
+            # padded steps: k=0, w=1 -> wkv state passes through unchanged
+            valid = (jnp.arange(T)[None, :] < chunk_lens[:, None])[..., None, None]
+            k = jnp.where(valid, k, 0.0)
+            w = jnp.where(valid, w, 1.0)
 
-    s0 = (cache["wkv"].astype(jnp.float32) if cache is not None
-          else jnp.zeros((B, H, N, N), jnp.float32))
-    if impl == "pallas":
-        from repro.kernels.rwkv6_wkv import ops as wkv_ops
-        y, s_fin = wkv_ops.rwkv6_wkv(r, k, v, w, tm["u"], s0)
-    else:
-        y, s_fin = wkv_scan(r, k, v, w, tm["u"], s0, sharder=sharder)
+        s0 = (cache["wkv"].astype(jnp.float32) if cache is not None
+              else jnp.zeros((B, H, N, N), jnp.float32))
+        if impl == "pallas":
+            from repro.kernels.rwkv6_wkv import ops as wkv_ops
+            y, s_fin = wkv_ops.rwkv6_wkv(r, k, v, w, tm["u"], s0)
+        else:
+            y, s_fin = wkv_scan(r, k, v, w, tm["u"], s0, sharder=sharder)
 
-    # per-head groupnorm, gate, output proj
-    yf = y.reshape(B, T, H, N)
-    mu = jnp.mean(yf, axis=-1, keepdims=True)
-    var = jnp.var(yf, axis=-1, keepdims=True)
-    yf = (yf - mu) * jax.lax.rsqrt(var + 64e-5)
-    yf = yf.reshape(B, T, d) * p["time_mix"]["ln_x"]["scale"] + tm["ln_x"]["bias"]
-    hetero.record_nonlinear(yf.size)
-    att = hetero.static_matmul((yf.astype(x.dtype) * g), tm["o_proj"],
-                               noise=noise, rng=rng)
-    if lora is not None and "wo" in lora:
-        att = att + lora_delta(yf.astype(x.dtype) * g, lora["wo"], scale,
-                               adapter_idx)
-    x = x + att
+        # per-head groupnorm, gate, output proj
+        yf = y.reshape(B, T, H, N)
+        mu = jnp.mean(yf, axis=-1, keepdims=True)
+        var = jnp.var(yf, axis=-1, keepdims=True)
+        yf = (yf - mu) * jax.lax.rsqrt(var + 64e-5)
+        yf = yf.reshape(B, T, d) * p["time_mix"]["ln_x"]["scale"] + tm["ln_x"]["bias"]
+        hetero.record_nonlinear(yf.size)
+        att = hetero.static_matmul((yf.astype(x.dtype) * g), tm["o_proj"],
+                                   noise=noise, rng=rng)
+        if lora is not None and "wo" in lora:
+            att = att + lora_delta(yf.astype(x.dtype) * g, lora["wo"], scale,
+                                   adapter_idx)
+        x = x + att
 
     # ---------------- channel mix ----------------
-    cm = p["channel_mix"]
-    xn2 = layers.layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
-    xx2 = _token_shift(xn2, cache["shift_c"] if cache is not None else None)
-    xk = xn2 + (xx2 - xn2) * cm["mu_k"]
-    xr = xn2 + (xx2 - xn2) * cm["mu_r"]
-    kf = hetero.static_matmul(xk, cm["ck_proj"], noise=noise, rng=rng)
-    kf = jnp.square(jax.nn.relu(kf))
-    hetero.record_nonlinear(kf.size)
-    vf = hetero.static_matmul(kf, cm["cv_proj"], noise=noise, rng=rng)
-    rg = jax.nn.sigmoid(hetero.static_matmul(xr, cm["cr_proj"],
-                                             noise=noise, rng=rng))
-    x = x + rg * vf
+    with jax.named_scope(obs.MLP):
+        cm = p["channel_mix"]
+        xn2 = layers.layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
+        xx2 = _token_shift(xn2, cache["shift_c"] if cache is not None else None)
+        xk = xn2 + (xx2 - xn2) * cm["mu_k"]
+        xr = xn2 + (xx2 - xn2) * cm["mu_r"]
+        kf = hetero.static_matmul(xk, cm["ck_proj"], noise=noise, rng=rng)
+        kf = jnp.square(jax.nn.relu(kf))
+        hetero.record_nonlinear(kf.size)
+        vf = hetero.static_matmul(kf, cm["cv_proj"], noise=noise, rng=rng)
+        rg = jax.nn.sigmoid(hetero.static_matmul(xr, cm["cr_proj"],
+                                                 noise=noise, rng=rng))
+        x = x + rg * vf
 
     new_cache = None
     if cache is not None:

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import hetero
 from repro.core.lora import scan_period
@@ -109,44 +110,45 @@ def _apply_position(cfg: ModelConfig, ec: ExecConfig, pos: int, x: Array,
             chunk_lens=chunk_lens)
         return ec.shard(x, "act"), newc, aux
 
-    h = ec.shard(layers.apply_norm(cfg, pparams["norm"], x), "act")
-    if kind == "attn":
-        delta, newc = attention.apply_attention_block(
-            cfg, pparams["attn"], h, positions,
-            kind=cfg.attn_kind(pos), mode=mode, cache=pcache,
-            prefill_cache_len=prefill_cache_len, lora=plora,
-            adapter_idx=adapter_idx, noise=noise, rng=rng,
-            impl=ec.attn_impl, block_q=ec.block_q, block_kv=ec.block_kv,
-            sharder=ec.sharder, paged=paged,
-            chunk_lens=chunk_lens if mode == "prefill" else None)
-    elif kind == "mamba":
-        h = ec.shard(h, "act_gathered")  # scan has cross-shard seq dependency
-        delta, newc = ssm.apply_mamba_block(
-            cfg, pparams["mamba"], h, cache=pcache, lora=plora,
-            adapter_idx=adapter_idx, noise=noise, rng=rng, sharder=ec.sharder,
-            chunk_lens=chunk_lens)
-        delta = ec.shard(delta, "act")
-    else:
-        raise KeyError(kind)
-    x = x + delta
-    x = ec.shard(x, "act")
+    with jax.named_scope(obs.ATTN if kind == "attn" else obs.RECURRENT):
+        h = ec.shard(layers.apply_norm(cfg, pparams["norm"], x), "act")
+        if kind == "attn":
+            delta, newc = attention.apply_attention_block(
+                cfg, pparams["attn"], h, positions,
+                kind=cfg.attn_kind(pos), mode=mode, cache=pcache,
+                prefill_cache_len=prefill_cache_len, lora=plora,
+                adapter_idx=adapter_idx, noise=noise, rng=rng,
+                impl=ec.attn_impl, block_q=ec.block_q, block_kv=ec.block_kv,
+                sharder=ec.sharder, paged=paged,
+                chunk_lens=chunk_lens if mode == "prefill" else None)
+        elif kind == "mamba":
+            # the scan has a cross-shard sequence dependency
+            h = ec.shard(h, "act_gathered")
+            delta, newc = ssm.apply_mamba_block(
+                cfg, pparams["mamba"], h, cache=pcache, lora=plora,
+                adapter_idx=adapter_idx, noise=noise, rng=rng,
+                sharder=ec.sharder, chunk_lens=chunk_lens)
+            delta = ec.shard(delta, "act")
+        else:
+            raise KeyError(kind)
+        x = ec.shard(x + delta, "act")
 
-    h2 = ec.shard(layers.apply_norm(cfg, pparams["norm2"], x), "act")
-    if cfg.is_moe_layer(pos):
-        token_mask = None
-        if chunk_lens is not None:
-            token_mask = (jnp.arange(x.shape[1])[None, :]
-                          < chunk_lens[:, None])
-        ff_out, aux = moe.apply_moe(cfg, pparams["ff"], h2, noise=noise,
-                                    rng=rng, capacity_factor=ec.capacity_factor,
-                                    sharder=ec.sharder,
-                                    group_size=ec.moe_group_size,
-                                    token_mask=token_mask,
-                                    dispatch=ec.moe_dispatch)
-    else:
-        ff_out = layers.apply_mlp(cfg, pparams["ff"], h2, noise=noise, rng=rng,
-                                  sharder=ec.sharder)
-    x = ec.shard(x + ff_out, "act")
+    with jax.named_scope(obs.MLP):
+        h2 = ec.shard(layers.apply_norm(cfg, pparams["norm2"], x), "act")
+        if cfg.is_moe_layer(pos):
+            token_mask = None
+            if chunk_lens is not None:
+                token_mask = (jnp.arange(x.shape[1])[None, :]
+                              < chunk_lens[:, None])
+            ff_out, aux = moe.apply_moe(
+                cfg, pparams["ff"], h2, noise=noise, rng=rng,
+                capacity_factor=ec.capacity_factor, sharder=ec.sharder,
+                group_size=ec.moe_group_size, token_mask=token_mask,
+                dispatch=ec.moe_dispatch)
+        else:
+            ff_out = layers.apply_mlp(cfg, pparams["ff"], h2, noise=noise,
+                                      rng=rng, sharder=ec.sharder)
+        x = ec.shard(x + ff_out, "act")
     return x, newc, aux
 
 
@@ -175,11 +177,12 @@ def forward(cfg: ModelConfig, params: Dict, inputs: Dict[str, Array], *,
     P = scan_period(cfg)
     n_sp = cfg.n_layers // P
 
-    if "tokens" in inputs:
-        x = layers.embed_tokens(cfg, params["embed"], inputs["tokens"],
-                                ec.act_dtype)
-    else:
-        x = inputs["embeds"].astype(ec.act_dtype)
+    with jax.named_scope(obs.EMBED):
+        if "tokens" in inputs:
+            x = layers.embed_tokens(cfg, params["embed"], inputs["tokens"],
+                                    ec.act_dtype)
+        else:
+            x = inputs["embeds"].astype(ec.act_dtype)
     B, T = x.shape[0], x.shape[1]
 
     if positions is None:
@@ -258,10 +261,11 @@ def forward(cfg: ModelConfig, params: Dict, inputs: Dict[str, Array], *,
             new_cache_layers = jax.tree.map(
                 lambda *xs: jnp.stack(xs), *new_cache_layers)
 
-    x = layers.apply_norm(cfg, params["final_norm"], x)
-    x = ec.shard(x, "act_gathered")
-    logits = layers.unembed(cfg, params["embed"], x)
-    logits = ec.shard(logits, "logits")
+    with jax.named_scope(obs.HEAD):
+        x = layers.apply_norm(cfg, params["final_norm"], x)
+        x = ec.shard(x, "act_gathered")
+        logits = layers.unembed(cfg, params["embed"], x)
+        logits = ec.shard(logits, "logits")
 
     new_cache = None
     if mode in ("prefill", "decode"):

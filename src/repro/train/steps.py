@@ -14,6 +14,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tfm
 from repro.models.transformer import ExecConfig
@@ -40,8 +41,9 @@ def make_loss_fn(cfg: ModelConfig, ec: ExecConfig):
                   else {"embeds": micro["embeds"]})
         logits, _, aux = tfm.forward(cfg, params, inputs, lora=lora,
                                      mode="train", exec_cfg=ec, rng=rng)
-        loss, metrics = tfm.lm_loss(cfg, logits, micro["labels"],
-                                    micro.get("mask"))
+        with jax.named_scope(obs.LOSS):
+            loss, metrics = tfm.lm_loss(cfg, logits, micro["labels"],
+                                        micro.get("mask"))
         return loss, {**metrics, "lb_loss": aux["lb_loss"]}
     return loss_fn
 
@@ -75,8 +77,9 @@ def make_train_step(cfg: ModelConfig, ec: ExecConfig, hp: TrainHParams
             metrics: Dict[str, Array] = {}
         else:
             (loss, metrics), grads = grad_fn(lora, params, batch, rng)
-        new_lora, new_opt, om = adamw.apply_updates(hp.adamw, lora, grads,
-                                                    opt_state)
+        with jax.named_scope(obs.OPTIMIZER):
+            new_lora, new_opt, om = adamw.apply_updates(hp.adamw, lora, grads,
+                                                        opt_state)
         return new_lora, new_opt, {"loss": loss, **metrics, **om}
 
     return step
