@@ -64,6 +64,23 @@ def _record(cls: str, flops: float) -> None:
         _TALLY.active[cls] += float(flops)
 
 
+@contextlib.contextmanager
+def repeated(n: int):
+    """Count what is traced inside ``n`` times: a scan body is traced once
+    and runs once per step of the scan."""
+    outer = _TALLY.active
+    if outer is None:
+        yield
+        return
+    _TALLY.active = dict.fromkeys(outer, 0.0)
+    try:
+        yield
+    finally:
+        inner, _TALLY.active = _TALLY.active, outer
+        for cls, v in inner.items():
+            outer[cls] += n * v
+
+
 def record_nonlinear(elements: int) -> None:
     """Softmax / layernorm / activation element counts (MHA-3, L-1, L-2)."""
     _record("nonlinear", float(elements))

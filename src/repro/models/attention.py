@@ -12,6 +12,11 @@ Three interchangeable implementations of the fused score+softmax+V step
   * pallas      — TPU kernel (repro.kernels.flash_attention), selected via
                   ``impl='pallas'``; checked against ``ref`` in interpret
                   mode and compiled for v5e in tests/test_tpu_compile.py.
+  * fused       — the same kernels forward and backward (``fused_attention``),
+                  taken in place of ``blocked`` wherever they apply: lowered
+                  for a TPU, unsharded causal self-attention (T == S, at a
+                  length, head ratio and width ``causal_blocks`` takes)
+                  without window or softcap.
 
 Supports GQA (any q/kv head ratio), causal masking via explicit position
 arrays (required under sequence-parallel Q sharding), sliding windows,
@@ -24,11 +29,13 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import hetero
 from repro.core.lora import lora_delta, lora_scale
 from repro.core.noise import NoiseConfig
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.models import layers
 
 Array = jax.Array
@@ -141,7 +148,8 @@ def _flash_fwd_scoped(q, k, v, q_pos, kv_pos, window, softcap, block_kv,
             preferred_element_type=jnp.float32)
         return (m_new, l, acc), None
 
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, pb))
+    with hetero.repeated(kb.shape[0]):
+        (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, pb))
     lse = m + jnp.log(jnp.maximum(l, 1e-30))          # (B,Hkv,G,T)
     out = acc / jnp.maximum(l, 1e-30).transpose(0, 3, 1, 2)[..., None]
     out = out.reshape(B, T, Hq, D).astype(q.dtype)
@@ -226,14 +234,14 @@ def _flash_bwd_scoped(window, softcap, block_kv, sharder, folded, res, dout):
                                      preferred_element_type=jnp.float32)
         return dq, (dk_b, dv_b)
 
-    dq, (dk_s, dv_s) = jax.lax.scan(body, dq0, (kb, vb, pb))
+    with hetero.repeated(kb.shape[0]):
+        dq, (dk_s, dv_s) = jax.lax.scan(body, dq0, (kb, vb, pb))
     dq = (dq * c).reshape(B, T, Hq, D).astype(q.dtype)
     nb = dk_s.shape[0]
     dk = dk_s.transpose(1, 0, 2, 3, 4).reshape(B, nb * block_kv, Hkv, D)
     dv = dv_s.transpose(1, 0, 2, 3, 4).reshape(B, nb * block_kv, Hkv, D)
     dk = dk[:, :S].astype(k.dtype)
     dv = dv[:, :S].astype(v.dtype)
-    import numpy as np
     zpos = np.zeros(q_pos.shape, jax.dtypes.float0)
     zkpos = np.zeros(kv_pos.shape, jax.dtypes.float0)
     return dq, dk, dv, zpos, zkpos
@@ -251,6 +259,92 @@ def blocked_attention(q: Array, k: Array, v: Array, q_pos: Array,
     O(T*S) compute, O(T + block) memory in both passes."""
     return _flash(q, k, v, q_pos, kv_pos, window, softcap, block_kv, sharder,
                   folded)
+
+
+def _kernels_fwd(q, k, v, q_pos, kv_pos, interpret=False):
+    """The fused kernels' forward: (out, lse), lse float32 (B, Hkv, G, T)
+    as ``_flash_fwd_impl`` gives it."""
+    B, T, Hq, _ = q.shape
+    Hkv = k.shape[2]
+    with jax.named_scope("flash_fused"):
+        out, lse = fa_ops.causal_attention_fwd(q, k, v, q_pos, kv_pos,
+                                               interpret=interpret)
+    return out, lse.reshape(B, Hkv, Hq // Hkv, T)
+
+
+def _kernels_bwd(res, dout, interpret=False):
+    """(dq, dk, dv) from the residuals ``_flash_fwd`` keeps."""
+    q, k, v, q_pos, kv_pos, out, lse = res
+    B, T, Hq, _ = q.shape
+    with jax.named_scope("flash_fused"):
+        return fa_ops.causal_attention_bwd(
+            q, k, v, q_pos, kv_pos, out, lse.reshape(B, Hq, T), dout,
+            interpret=interpret)
+
+
+def _no_pos_grads(res):
+    return (np.zeros(res[3].shape, jax.dtypes.float0),
+            np.zeros(res[4].shape, jax.dtypes.float0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def fused_attention(q: Array, k: Array, v: Array, q_pos: Array,
+                    kv_pos: Array, interpret: bool = False) -> Array:
+    """Causal self-attention as fused Pallas kernels, forward and backward,
+    where ``fa_ops.causal_blocks`` applies: scores never leave VMEM and
+    (q, kv) block pairs the mask hides are skipped. ``attend`` takes the
+    same kernels on a TPU through ``_causal_self_attention``."""
+    return _kernels_fwd(q, k, v, q_pos, kv_pos, interpret)[0]
+
+
+def _fused_fwd(q, k, v, q_pos, kv_pos, interpret):
+    out, lse = _kernels_fwd(q, k, v, q_pos, kv_pos, interpret)
+    return out, (q, k, v, q_pos, kv_pos, out, lse)
+
+
+def _fused_bwd(interpret, res, dout):
+    return (*_kernels_bwd(res, dout, interpret), *_no_pos_grads(res))
+
+
+fused_attention.defvjp(_fused_fwd, _fused_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _causal_self_attention(q, k, v, q_pos, kv_pos, block_kv):
+    """``fused_attention`` where the call is lowered for a TPU, else
+    ``blocked_attention``. Each rule stages both and the lowering keeps
+    one; the blocked path, traced once per rule, keeps the operation
+    tally for either."""
+    return _causal_fwd(q, k, v, q_pos, kv_pos, block_kv)[0]
+
+
+def _causal_fwd(q, k, v, q_pos, kv_pos, block_kv):
+    out, lse = jax.lax.platform_dependent(
+        q, k, v, q_pos, kv_pos, tpu=_kernels_fwd,
+        default=functools.partial(_flash_fwd_impl, window=None, softcap=None,
+                                  block_kv=block_kv))
+    return out, (q, k, v, q_pos, kv_pos, out, lse)
+
+
+def _causal_bwd(block_kv, res, dout):
+    dq, dk, dv = jax.lax.platform_dependent(
+        res, dout, tpu=_kernels_bwd,
+        default=lambda res, dout: _flash_bwd(None, None, block_kv, None,
+                                             False, res, dout)[:3])
+    return dq, dk, dv, *_no_pos_grads(res)
+
+
+_causal_self_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
+def _fused_applies(q_shape, k_shape, window, softcap, sharder) -> bool:
+    """Whether ``fused_attention`` can stand in for ``blocked_attention``
+    on a TPU: unsharded causal self-attention without window or softcap,
+    at a length, head ratio and width the kernels take."""
+    (_, T, Hq, D), S = q_shape, k_shape[1]
+    return (sharder is None and window is None and softcap is None
+            and T == S
+            and fa_ops.causal_blocks(T, Hq // k_shape[2], D) is not None)
 
 
 def banded_attention(q: Array, k: Array, v: Array, q_pos: Array,
@@ -307,7 +401,6 @@ def attend(q, k, v, q_pos, kv_pos, *, kind: str, window: Optional[int],
     if window is not None and window >= S:
         window = None   # sliding degenerates to full causal
     if impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
         return fa_ops.flash_attention(q, k, v, q_pos, kv_pos, window=window,
                                       softcap=softcap)
     if impl == "ref" or T == 1 or S <= block_kv:
@@ -318,6 +411,8 @@ def attend(q, k, v, q_pos, kv_pos, *, kind: str, window: Optional[int],
         return banded_attention(q, k, v, q_pos, kv_pos, window=window,
                                 softcap=softcap, block_q=block_q,
                                 block_kv=block_kv, sharder=sharder)
+    if _fused_applies(q.shape, k.shape, window, softcap, sharder):
+        return _causal_self_attention(q, k, v, q_pos, kv_pos, block_kv)
     return blocked_attention(q, k, v, q_pos, kv_pos, window=window,
                              softcap=softcap, block_kv=block_kv,
                              sharder=sharder)

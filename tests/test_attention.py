@@ -100,3 +100,89 @@ def test_ring_cache_decode_matches_full_history():
                                    mode="decode", cache=cache, exec_cfg=ec)
         errs.append(float(jnp.max(jnp.abs(lg[:, -1] - full[:, t]))))
     assert max(errs) < 2e-4, errs
+
+
+ROUTES = {"fused": ("_kernels_fwd",),
+          "blocked": ("blocked_attention", "_flash_fwd_impl"),
+          "banded": ("banded_attention",), "ref": ("ref_attention",)}
+
+
+@pytest.mark.parametrize("case,platform,expect", [
+    ("self", "cpu", "blocked"),
+    ("self", "tpu", "fused"),
+    ("softcap", "tpu", "blocked"),
+    ("window", "tpu", "banded"),
+    ("sharder", "tpu", "blocked"),
+    ("decode", "tpu", "ref"),
+    ("t_ne_s", "tpu", "blocked"),
+    ("wide_groups", "tpu", "blocked"),
+])
+def test_attend_takes_fused_kernels_only_where_they_apply(monkeypatch, case,
+                                                          platform, expect):
+    """The fused kernels stand in for ``blocked`` only where the call is
+    lowered for a TPU, for unsharded causal self-attention without softcap
+    or window at a head ratio the kernels take; every other case keeps its
+    path. Each route is replaced by one that returns its own constant, and
+    the program lowered for ``platform`` holds the constant of the route
+    taken, and no other. (``_kernels_fwd`` and ``_flash_fwd_impl`` are the
+    two forwards of the self-attention route, with its log-sum-exp.)"""
+    for i, fns in enumerate(ROUTES.values()):
+        for fn in fns:
+            def route(q, k, *a, _c=11.0 * (i + 1), _fn=fn, **kw):
+                out = jnp.full(q.shape, _c, q.dtype)
+                if _fn.startswith("_"):
+                    B, T, Hq, _ = q.shape
+                    Hkv = k.shape[2]
+                    return out, jnp.zeros((B, Hkv, Hq // Hkv, T))
+                return out
+            monkeypatch.setattr(attn, fn, route)
+    T, S = {"decode": (1, 256), "t_ne_s": (128, 256)}.get(case, (256, 256))
+    Hq = 32 if case == "wide_groups" else 4
+    q, k, v, qp, kp = _qkv(1, T, S, Hq, 2, 16)
+
+    def f(q, k, v):
+        return attn.attend(
+            q, k, v, qp, kp, kind="sliding" if case == "window" else "full",
+            window=64 if case == "window" else None,
+            softcap=30.0 if case == "softcap" else None, impl="auto",
+            block_q=64, block_kv=64,
+            sharder=(lambda x, name: x) if case == "sharder" else None)
+
+    txt = jax.jit(f).trace(q, k, v).lower(
+        lowering_platforms=(platform,)).as_text()
+    lowered = [name for i, name in enumerate(ROUTES)
+               if f"dense<{11.0 * (i + 1):e}>" in txt]
+    assert lowered == [expect]
+    if platform == "cpu":
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(f)(q, k, v)),
+            11.0 * (list(ROUTES).index(expect) + 1))
+
+
+def test_fused_attention_tallies_like_blocked():
+    """On the fused route the operation tally is the blocked path's, and
+    that counts every KV block: 2 products of 2*B*T*Hq*S*D FLOPs forward,
+    5 more backward."""
+    from repro.core import hetero
+    B, T, Hq, Hkv, D = 2, 256, 4, 2, 16
+    q, k, v, qp, kp = _qkv(B, T, T, Hq, Hkv, D)
+
+    def value_of(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, qp, kp))
+
+    def grad_of(fn):
+        return jax.grad(value_of(fn), argnums=(0, 1, 2))
+
+    def fused_route(*a):
+        return attn.attend(*a, kind="full", window=None, softcap=None,
+                           impl="auto", block_q=64, block_kv=64)
+
+    def blocked(*a):
+        return attn.blocked_attention(*a, block_kv=64)
+
+    for f, products in ((value_of, 2), (grad_of, 7)):
+        rf = hetero.breakdown_of(f(fused_route), q, k, v)
+        rb = hetero.breakdown_of(f(blocked), q, k, v)
+        assert rf.dynamic_flops == rb.dynamic_flops == (
+            products * 2.0 * B * T * Hq * T * D)
+        assert rf.nonlinear_elems == rb.nonlinear_elems

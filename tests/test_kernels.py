@@ -1,6 +1,8 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles. The kernels run
 in Pallas interpret mode here (``interpret=True``): the CPU cannot run a
 Mosaic kernel; tests/test_tpu_compile.py compiles them for the TPU."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,9 +10,9 @@ import pytest
 
 from repro.core.quant import quantize
 from repro.kernels.crossbar_matmul import ops as cb_ops, ref as cb_ref
-from repro.kernels.flash_attention import ops as fa_ops
+from repro.kernels.flash_attention import kernel as fa_kernel, ops as fa_ops
 from repro.kernels.rwkv6_wkv import ops as wkv_ops
-from repro.models.attention import ref_attention
+from repro.models.attention import fused_attention, ref_attention
 from repro.models.rwkv import wkv_scan
 
 KEY = jax.random.PRNGKey(7)
@@ -84,6 +86,122 @@ def test_flash_attention_invalid_slots_masked():
     o2 = fa_ops.flash_attention(q, k2, v2, qpos, kpos, block_q=8, block_kv=8,
                                 interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
+
+
+def _rounded_like_the_kernels(q, k, v, pos, kpos, dout):
+    """Attention, its log-sum-exp (B*Hq, T) and (dq, dk, dv) for the
+    cotangent ``dout``, each dot's operands rounded to bfloat16 where the
+    kernels round them and float32 elsewhere. Exact only where each query
+    row sees one kv block (no rescaling between blocks)."""
+    bf, f32 = (lambda x: x.astype(jnp.bfloat16)), jnp.float32
+    B, T, Hq, D = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    qs = q.reshape(B, T, Hkv, G, D) * D ** -0.5
+    do = dout.reshape(B, T, Hkv, G, D)
+    mask = ((kpos[:, None, :] <= pos[:, :, None])
+            & (kpos[:, None, :] >= 0))[:, None, None]
+    s = jnp.einsum("bthgd,bshd->bhgts", bf(qs), bf(k),
+                   preferred_element_type=f32)
+    s = jnp.where(mask, s, fa_kernel.NEG_INF)
+    m = s.max(-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    l = p.sum(-1, keepdims=True)
+    o = jnp.einsum("bhgts,bshd->bthgd", bf(p), bf(v),
+                   preferred_element_type=f32)
+    o = o / l[..., 0].transpose(0, 3, 1, 2)[..., None]
+    lse = m + jnp.log(l)
+    pt = jnp.where(mask, jnp.exp(s - lse), 0.0)
+    di = jnp.sum(do * o, -1).transpose(0, 2, 3, 1)[..., None]
+    ds = pt * (jnp.einsum("bthgd,bshd->bhgts", bf(do), bf(v),
+                          preferred_element_type=f32) - di)
+    dq = jnp.einsum("bhgts,bshd->bthgd", bf(ds), bf(k),
+                    preferred_element_type=f32) * D ** -0.5
+    dk = jnp.einsum("bhgts,bthgd->bshd", bf(ds), bf(qs),
+                    preferred_element_type=f32)
+    dv = jnp.einsum("bhgts,bthgd->bshd", bf(pt), bf(do),
+                    preferred_element_type=f32)
+    return (o.reshape(B, T, Hq, D), lse.reshape(B * Hq, T),
+            (dq.reshape(B, T, Hq, D), dk, dv))
+
+
+def _rel_l2(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("B,T,Hq,Hkv,D,invalid,dots", [
+    (2, 640, 8, 2, 64, None, None),        # GQA 4:1, five blocks of 128
+    (1, 768, 2, 2, 128, None, None),       # GQA 1:1, three blocks of 256
+    (2, 640, 4, 1, 64, (100, 150), None),  # invalid slots inside a block
+    (1, 768, 4, 2, 128, (256, 512), None),  # a whole kv block invalid
+    (1, 1024, 4, 1, 128, None, None),      # two blocks of 512
+    (1, 1024, 8, 1, 64, None, None),       # GQA 8:1, q blocks of 256
+    (1, 512, 4, 1, 128, None, "bf16"),     # the chip's bfloat16 dots
+])
+def test_fused_attention_forward_and_grads_match_ref(B, T, Hq, Hkv, D,
+                                                     invalid, dots):
+    """The fused kernels' output, log-sum-exp and dQ, dK, dV against
+    ref_attention (the gradients through the custom VJP). Every case of
+    several kv blocks has (q, kv) block pairs the mask hides entirely,
+    which the kernels skip. With ``dots="bf16"`` the kernels cast each
+    dot's operands to bfloat16 as compiled for the TPU, and are held to a
+    reference that rounds at the same points, closely enough that a
+    missing or misplaced cast fails."""
+    ks = jax.random.split(jax.random.fold_in(KEY, T * Hq + D), 4)
+    q = jax.random.normal(ks[0], (B, T, Hq, D))
+    k = jax.random.normal(ks[1], (B, T, Hkv, D))
+    v = jax.random.normal(ks[2], (B, T, Hkv, D))
+    w = jax.random.normal(ks[3], (B, T, Hq, D))
+    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+    kpos = pos
+    if invalid is not None:
+        lo, hi = invalid
+        kpos = jnp.where((pos >= lo) & (pos < hi), -1, pos)
+    (bq, bk), _, _ = fa_ops.causal_blocks(T, Hq // Hkv, D)
+    assert (T == bk or (fa_kernel.block_visibility(pos, kpos, bq, bk)
+                        == fa_kernel.SKIP).any())
+
+    if dots == "bf16":
+        assert T == bk
+        o, lse = fa_ops.causal_attention_fwd(q, k, v, pos, kpos,
+                                             interpret=True,
+                                             dot_dtype=jnp.bfloat16)
+        grads = fa_ops.causal_attention_bwd(q, k, v, pos, kpos, o, lse, w,
+                                            interpret=True,
+                                            dot_dtype=jnp.bfloat16)
+        o_ref, lse_ref, g_ref = _rounded_like_the_kernels(q, k, v, pos,
+                                                          kpos, w)
+        assert _rel_l2(o, o_ref) < 1e-5
+        assert _rel_l2(lse.reshape(B * Hq, T), lse_ref) < 1e-6
+        # relative L2 with the casts as compiled: output 7e-8, gradients
+        # up to 7e-6; without them, or with q cast before its scale, 3e-3
+        for a, b in zip(grads, g_ref):
+            assert _rel_l2(a, b) < 1e-4
+        return
+
+    o, lse = fa_ops.causal_attention_fwd(q, k, v, pos, kpos, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(ref_attention(q, k, v, pos, kpos)),
+        rtol=1e-5, atol=1e-5)
+    qg = q.reshape(B, T, Hkv, Hq // Hkv, D) * D ** -0.5
+    s = jnp.einsum("bthgd,bshd->bhgts", qg, k,
+                   precision=jax.lax.Precision.HIGHEST)
+    mask = (kpos[:, None, :] <= pos[:, :, None]) & (kpos[:, None, :] >= 0)
+    s = jnp.where(mask[:, None, None], s, -jnp.inf)
+    lse_ref = jax.nn.logsumexp(s, axis=-1).reshape(B * Hq, T)
+    np.testing.assert_allclose(np.asarray(lse.reshape(B * Hq, T)),
+                               np.asarray(lse_ref), rtol=1e-5, atol=1e-5)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, pos, kpos) * w)
+
+    g_ref = jax.grad(loss(ref_attention), argnums=(0, 1, 2))(q, k, v)
+    g_ker = jax.grad(loss(functools.partial(fused_attention, interpret=True)),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ker, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4)
 
 
 @pytest.mark.parametrize("B,T,H,N,bt", [(2, 96, 4, 16, 32), (1, 64, 2, 32, 64),

@@ -10,15 +10,27 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
+from repro.configs import get_config, reduce_config
+from repro.core import lora as lora_lib
 from repro.core.quant import quantize
 from repro.kernels.crossbar_matmul import ops as cb_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.rwkv6_wkv import ops as wkv_ops
+from repro.models import attention as attn
+from repro.models import transformer as tfm
+from repro.optim import adamw
+from repro.train.steps import TrainHParams, make_train_step
+
+FUSED_KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 
 
 @pytest.fixture(scope="module")
@@ -68,6 +80,84 @@ def test_flash_attention_compiles_llama3_2_1b_prefill(one_chip, dtype):
         lambda q, k, v, qp, kp: fa_ops.flash_attention(q, k, v, qp, kp),
         args, one_chip)
     assert "tpu_custom_call" in txt
+
+
+def _kernel_calls(txt):
+    """{instruction name: op_name} of every Pallas kernel in compiled text."""
+    calls = {}
+    for line in txt.splitlines():
+        m = re.match(r"\s*%?([\w.-]+) = .*custom_call_target=\"tpu_custom_call\"",
+                     line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            calls[m.group(1)] = op.group(1) if op else ""
+    return calls
+
+
+def _innermost_layer(op_name):
+    """The innermost layer scope on an op_name path, with the autodiff
+    wrappers (``jvp(...)``, ``transpose(...)``) taken off each part."""
+    found = None
+    for part in op_name.split("/"):
+        while (m := re.match(r"^(?:transpose|jvp|vmap)\((.*)\)$", part)):
+            part = m.group(1)
+        if part in obs.LAYERS:
+            found = part
+    return found
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,D", [
+    (8, 32, 8, 128),     # mistral-nemo-12b, the benchmark's batch
+    (1, 64, 8, 128),     # chameleon-34b, jamba-1.5-large: G = 8
+    (1, 48, 8, 128),     # mixtral-8x22b, internlm2-20b: G = 6
+    (1, 32, 8, 64),      # llama3.2-1b: D = 64
+])
+def test_fused_attention_compiles_nemo_train_shapes(one_chip, B, Hq, Hkv, D):
+    """Training attention through ``attend``, T = S = 1024, float32, value
+    and gradient, at mistral-nemo-12b's heads and at the other head ratios
+    and widths the kernels take. The three kernels are there by name,
+    within VMEM, with no conditional left from the platform choice and no
+    (..., 1024, 512) or (..., 1024, 1024) float32 score array."""
+    T = 1024
+    args = (jax.ShapeDtypeStruct((B, T, Hq, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, T, Hkv, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, T, Hkv, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, T), jnp.int32))
+
+    def f(q, k, v, pos):
+        def value(q, k, v):
+            return jnp.sum(attn.attend(
+                q, k, v, pos, pos, kind="full", window=None, softcap=None,
+                impl="auto", block_q=512, block_kv=512) ** 2)
+        return jax.value_and_grad(value, argnums=(0, 1, 2))(q, k, v)
+
+    txt = _compile_text(f, args, one_chip)
+    names = {n.split(".")[0] for n in _kernel_calls(txt)}
+    assert names == set(FUSED_KERNELS)
+    assert " conditional(" not in txt
+    assert not re.search(r"f32\[(\d+,)*1024,(512|1024)\]", txt)
+
+
+def test_fused_attention_kernels_sit_under_attn_scope(one_chip):
+    """A one-layer train step at small width, compiled for the v5e, where
+    it takes the fused route: each kernel's op_name lies under the ``attn``
+    layer scope, so the device trace counts it as attention."""
+    cfg = dataclasses.replace(reduce_config(get_config("mistral-nemo-12b")),
+                              n_layers=1)
+    B, T = 2, 256
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda k: tfm.init_params(cfg, k), key)
+    lora = jax.eval_shape(lambda k: lora_lib.init_lora_params(cfg, k), key)
+    batch = {"tokens": jax.ShapeDtypeStruct((B, T), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((B, T), jnp.int32)}
+    step = make_train_step(cfg, tfm.ExecConfig(block_q=128, block_kv=128),
+                           TrainHParams())
+    txt = _compile_text(step, (params, lora, jax.eval_shape(adamw.init, lora),
+                               batch, key), one_chip)
+    calls = _kernel_calls(txt)
+    assert {n.split(".")[0] for n in calls} == set(FUSED_KERNELS)
+    for name, op_name in calls.items():
+        assert _innermost_layer(op_name) == obs.ATTN, (name, op_name)
 
 
 def test_rwkv6_wkv_compiles_rwkv6_7b(one_chip):
