@@ -21,6 +21,7 @@ _ARCH_MODULES = {
     "musicgen-medium": "musicgen_medium",
     "chameleon-34b": "chameleon_34b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
+    "jamba2-3b": "jamba2_3b",
     "rwkv6-7b": "rwkv6_7b",
 }
 

@@ -33,7 +33,7 @@ class AttnConfig:
     window: Optional[int] = None          # sliding-window size (tokens)
     logit_softcap: Optional[float] = None  # gemma2 attn softcap (50.0)
     qk_norm: bool = False                 # chameleon-style query/key norm
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None: no positional encoding
 
     def kind_for(self, attn_layer_idx: int) -> str:
         return self.pattern[attn_layer_idx % len(self.pattern)]
@@ -214,6 +214,7 @@ class ModelConfig:
                 total += d_in * mc.d_state       # A_log
                 total += d_in                    # D
                 total += d_in * d                # out_proj
+                total += r + 2 * mc.d_state      # dt/B/C norm scales
             elif kind == "rwkv":
                 rc = self.rwkv
                 total += 5 * d * d               # r,k,v,g(out-approx),o  time-mix

@@ -594,9 +594,10 @@ def apply_attention_block(
         q = _qk_norm(q, p["q_norm"], cfg.norm_eps)
         k = _qk_norm(k, p["k_norm"], cfg.norm_eps)
 
-    sin, cos = layers.rope_sincos(positions, cfg.hd, cfg.attn.rope_theta)
-    q = layers.apply_rope(q, sin, cos)
-    k = layers.apply_rope(k, sin, cos)
+    if cfg.attn.rope_theta is not None:   # None: position comes from elsewhere
+        sin, cos = layers.rope_sincos(positions, cfg.hd, cfg.attn.rope_theta)
+        q = layers.apply_rope(q, sin, cos)
+        k = layers.apply_rope(k, sin, cos)
 
     new_cache = None
     if mode == "decode" and paged is not None:

@@ -202,6 +202,19 @@ def forward(cfg: ModelConfig, params: Dict, inputs: Dict[str, Array], *,
     lora_layers = lora["layers"] if lora is not None else tuple({} for _ in range(P))
     cache_layers = cache["layers"] if cache is not None else tuple(None for _ in range(P))
 
+    apply_position = _apply_position
+    if ec.remat and P > 1:
+        # a period of several layers: keep one layer's activations live at a
+        # time in the backward pass, not the whole period's. This nests in
+        # the period's checkpoint below, so a layer's forward runs three
+        # times; dropping the period's checkpoint saves one run but keeps
+        # every layer's input: jamba2-3b at 1 x 8192 on a v5e then needs
+        # 6.27 GB of arguments and 10.32 GB of temporaries, over the chip's
+        # 15.75 GB, where the nesting needs 8.04 GB of temporaries
+        apply_position = jax.checkpoint(
+            _apply_position, static_argnums=(0, 1, 2, 8, 9),
+            policy=jax.checkpoint_policies.nothing_saveable)
+
     def period_fn(x, period_idx, pparams_t, plora_t, pcache_t, rng):
         new_caches = []
         all_aux = []
@@ -214,7 +227,7 @@ def forward(cfg: ModelConfig, params: Dict, inputs: Dict[str, Array], *,
                 from repro.models.kvcache import position_cache_spec
                 spec = position_cache_spec(cfg, pos, B, 1, ec.act_dtype)
                 pc = {k: jnp.zeros(s, d) for k, (s, d) in spec.items()}
-            x, newc, aux = _apply_position(
+            x, newc, aux = apply_position(
                 cfg, ec, pos, x, pparams_t[pos], plora_t[pos], pc,
                 positions, mode, prefill_cache_len, prng, adapter_idx,
                 paged, chunk_lens)

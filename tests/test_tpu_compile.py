@@ -160,6 +160,41 @@ def test_fused_attention_kernels_sit_under_attn_scope(one_chip):
         assert _innermost_layer(op_name) == obs.ATTN, (name, op_name)
 
 
+def test_jamba_train_step_keeps_its_layer_scopes(one_chip):
+    """One period of jamba2-3b at small width, remat on, compiled for the
+    v5e: with one KV head the attention's score and value products keep
+    their op_name under ``attn`` (the CPU compiler drops it), every matmul
+    lies under one layer scope, and the selective scan shows under
+    ``ssm_scan`` inside ``recurrent``."""
+    cfg = dataclasses.replace(reduce_config(get_config("jamba2-3b")),
+                              n_layers=14)
+    assert cfg.n_kv_heads == 1
+    B, T = 1, 256
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda k: tfm.init_params(cfg, k), key)
+    lora = jax.eval_shape(lambda k: lora_lib.init_lora_params(cfg, k), key)
+    batch = {"tokens": jax.ShapeDtypeStruct((B, T), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((B, T), jnp.int32)}
+    step = make_train_step(cfg, tfm.ExecConfig(remat=True), TrainHParams())
+    txt = _compile_text(step, (params, lora, jax.eval_shape(adamw.init, lora),
+                               batch, key), one_chip)
+    dots, scan = set(), 0
+    for line in txt.splitlines():
+        op = re.search(r'op_name="([^"]*)"', line)
+        if op is None:
+            continue
+        if re.match(r"\s*(ROOT )?%?[\w.-]+ = \S+ (dot|convolution)\(", line):
+            dots.add((_innermost_layer(op.group(1)),
+                      "->" in op.group(1).rsplit("/", 2)[-2]))
+        scan += "/recurrent/ssm_scan/" in op.group(1)
+    assert None not in {layer for layer, _ in dots}, dots
+    # the einsums of the attention core (``bthgd,bshd->bhgts`` and back)
+    assert (obs.ATTN, True) in dots
+    assert {obs.ATTN, obs.RECURRENT, obs.MLP, obs.HEAD} <= {
+        layer for layer, _ in dots}
+    assert scan > 0
+
+
 def test_rwkv6_wkv_compiles_rwkv6_7b(one_chip):
     """rwkv6-7b: 64 heads of N = 64, T = 1024."""
     B, T, H, N = 1, 1024, 64, 64
