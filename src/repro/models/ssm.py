@@ -3,13 +3,17 @@
 The projections (in/out/x/dt) are STATIC-engine matmuls (crossbar-
 quantizable frozen weights); the selective scan itself is a dynamic
 recurrence with no weight-stationary form — it runs on the DYNAMIC engine
-(DESIGN.md SS5). The scan is chunked: sequential ``lax.scan`` over chunks of
-``cfg.mamba.chunk`` steps carrying the (B, d_in, N) state, with a parallel
-``associative_scan`` inside each chunk — O(T) work, O(B*chunk*d_in*N)
-transient memory (d_in is TP-sharded so this divides by the mesh width).
+(DESIGN.md SS5). On a TPU, an unsharded scan of at least one time block runs
+as fused Pallas kernels, forward and backward (``kernels/selective_scan``),
+with the state in VMEM. Elsewhere the scan is chunked: sequential
+``lax.scan`` over chunks of ``cfg.mamba.chunk`` steps carrying the
+(B, d_in, N) state, with a parallel ``associative_scan`` inside each chunk —
+O(T) work, O(B*chunk*d_in*N) transient memory (d_in is TP-sharded so this
+divides by the mesh width).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -20,6 +24,7 @@ from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import hetero
 from repro.core.noise import NoiseConfig
+from repro.kernels.selective_scan import ops as ss_ops
 from repro.models import layers
 
 Array = jax.Array
@@ -93,8 +98,21 @@ def _causal_conv(x: Array, w: Array, b: Array, state: Optional[Array],
 
 def _selective_scan(dt: Array, Bc: Array, Cc: Array, xi: Array, A: Array,
                     h0: Array, chunk: int, sharder=None) -> Tuple[Array, Array]:
-    """Chunked selective scan: h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t;
+    """Selective scan: h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t;
     y_t = C_t . h_t.  dt/xi (B,T,D) f32; Bc/Cc (B,T,N); A (D,N); h0 (B,D,N).
+
+    Unsharded scans of at least one kernel time block go through
+    ``fused_scan`` (the Pallas kernels on a TPU); decode steps, short
+    chunks and a TP-sharded d_in keep ``_chunked_scan``."""
+    if sharder is None and ss_ops.applies(dt.shape[1]):
+        return fused_scan(dt, Bc, Cc, xi, A, h0, chunk)
+    return _chunked_scan(dt, Bc, Cc, xi, A, h0, chunk, sharder)
+
+
+def _chunked_scan(dt: Array, Bc: Array, Cc: Array, xi: Array, A: Array,
+                  h0: Array, chunk: int, sharder=None) -> Tuple[Array, Array]:
+    """The selective scan in XLA: sequential ``lax.scan`` over chunks, a
+    parallel ``associative_scan`` inside each.
 
     The (B, chunk, D, N) decay/increment tensors are built *inside* the
     checkpointed chunk body (never materialized for the whole sequence) and
@@ -137,6 +155,65 @@ def _selective_scan(dt: Array, Bc: Array, Cc: Array, xi: Array, A: Array,
                               to_chunks(xi)))
     y = ys.transpose(1, 0, 2, 3).reshape(B, nc * L, D)
     return y[:, :T], h_fin
+
+
+def _kernels_fwd(dt, Bc, Cc, xi, A, h0, interpret=False):
+    y, h_fin, hs = ss_ops.scan_fwd(dt, Bc, Cc, xi, A, h0, interpret=interpret)
+    return (y, h_fin), (dt, Bc, Cc, xi, A, h0, hs)
+
+
+def _chunked_fwd(dt, Bc, Cc, xi, A, h0, chunk):
+    """``_chunked_scan`` with residuals shaped as ``_kernels_fwd``'s; its
+    backward recomputes from the inputs, so the block states are zeros."""
+    hs = jnp.zeros(ss_ops.block_states_shape(dt.shape, A.shape[1]),
+                   jnp.float32)
+    return (_chunked_scan(dt, Bc, Cc, xi, A, h0, chunk),
+            (dt, Bc, Cc, xi, A, h0, hs))
+
+
+def _chunked_bwd(chunk, res, cts):
+    # the forward was counted when the forward rule traced it
+    with hetero.repeated(0):
+        _, vjp = jax.vjp(functools.partial(_chunked_scan, chunk=chunk),
+                         *res[:-1])
+    return vjp(cts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def fused_scan(dt, Bc, Cc, xi, A, h0, chunk, interpret=False):
+    """The selective scan as the fused Pallas kernels of
+    ``kernels/selective_scan`` where the call is lowered for a TPU, else as
+    ``_chunked_scan``. Each rule stages both and the lowering keeps one;
+    the chunked path keeps the operation tally, counted by the forward
+    rule only. ``interpret`` runs the kernels in the interpreter on any
+    platform."""
+    return _fused_fwd(dt, Bc, Cc, xi, A, h0, chunk, interpret)[0]
+
+
+def _fused_fwd(dt, Bc, Cc, xi, A, h0, chunk, interpret):
+    if interpret:
+        return _kernels_fwd(dt, Bc, Cc, xi, A, h0, interpret=True)
+    return jax.lax.platform_dependent(
+        dt, Bc, Cc, xi, A, h0, tpu=_kernels_fwd,
+        default=functools.partial(_chunked_fwd, chunk=chunk))
+
+
+def _fused_bwd(chunk, interpret, res, cts):
+    def kernels(res, cts, interpret=False):
+        dt, Bc, Cc, xi, A, _, hs = res
+        return ss_ops.scan_bwd(dt, Bc, Cc, xi, A, hs, *cts,
+                               interpret=interpret)
+
+    # the rule is traced outside ``apply_mamba_block``'s precision context
+    with jax.default_matmul_precision("highest"):
+        if interpret:
+            return kernels(res, cts, interpret=True)
+        return jax.lax.platform_dependent(
+            res, cts, tpu=kernels,
+            default=functools.partial(_chunked_bwd, chunk))
+
+
+fused_scan.defvjp(_fused_fwd, _fused_bwd)
 
 
 def apply_mamba_block(

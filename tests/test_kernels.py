@@ -2,6 +2,8 @@
 in Pallas interpret mode here (``interpret=True``): the CPU cannot run a
 Mosaic kernel; tests/test_tpu_compile.py compiles them for the TPU."""
 import functools
+import pathlib
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -12,9 +14,18 @@ from repro.core.quant import quantize
 from repro.kernels.crossbar_matmul import ops as cb_ops, ref as cb_ref
 from repro.kernels.flash_attention import kernel as fa_kernel, ops as fa_ops
 from repro.kernels.rwkv6_wkv import ops as wkv_ops
+from repro.kernels.selective_scan import ops as ss_ops, ref as ss_ref
 from repro.models.attention import fused_attention, ref_attention
 from repro.models.rwkv import wkv_scan
+from repro.models.ssm import fused_scan
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench.spec import BENCH, load_module  # noqa: E402
+
+JAMBA = load_module(BENCH / "reference" / "jamba.py")
 KEY = jax.random.PRNGKey(7)
 
 
@@ -219,3 +230,69 @@ def test_rwkv6_wkv_sweep(B, T, H, N, bt):
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_ref), rtol=1e-5,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("B,T,D,N,h0_scale,lens", [
+    (1, 200, 128, 16, 0.0, None),          # T not a multiple of the block
+    (1, 256, 200, 16, 0.0, None),          # D padded to a channel block
+    (2, 256, 256, 16, 1.0, None),          # a carried-in state
+    (2, 384, 128, 16, 0.0, (150, 384)),    # a row with dt = 0 after 150
+], ids=["t_tail", "d_tail", "h0", "dt0_rows"])
+def test_selective_scan_kernels_match_chunked_path_and_reference(
+        B, T, D, N, h0_scale, lens):
+    """``fused_scan`` through the interpreted kernels, forward and custom
+    VJP: y, the final state and the gradients in dt, B, C, x, A and h0
+    against the chunked XLA path (``jax.grad``) and, where the state
+    starts at zero, y and the gradients but h0 against the reference's
+    step-by-step recurrence, row by row."""
+    ks = jax.random.split(jax.random.fold_in(KEY, B * T + D), 8)
+    dt = jax.nn.softplus(jax.random.normal(ks[0], (B, T, D)) - 2.0)
+    if lens is not None:
+        dt = dt * (jnp.arange(T)[None, :] < jnp.array(lens)[:, None])[..., None]
+    Bc = jax.random.normal(ks[1], (B, T, N))
+    Cc = jax.random.normal(ks[2], (B, T, N))
+    x = jax.random.normal(ks[3], (B, T, D))
+    A = -jnp.exp(jax.random.normal(ks[4], (D, N)) * 0.3)
+    h0 = h0_scale * jax.random.normal(ks[5], (B, D, N))
+    dy = jax.random.normal(ks[6], (B, T, D))
+    dh = jax.random.normal(ks[7], (B, D, N))
+    args = (dt, Bc, Cc, x, A, h0)
+    assert ss_ops.applies(T)
+
+    def kernels(*a):
+        return fused_scan(*a, 64, True)
+
+    def chunked(*a):
+        return ss_ref.selective_scan_ref(*a, 64)
+
+    def loss(fn):
+        def value(*a):
+            y, h = fn(*a)
+            return jnp.sum(y * dy) + jnp.sum(h * dh)
+        return value
+
+    def close(got, want):
+        want = np.asarray(want)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5 * float(np.abs(want).max()))
+
+    for got, want in zip(kernels(*args), chunked(*args)):
+        close(got, want)
+    g_ker = jax.grad(loss(kernels), argnums=range(6))(*args)
+    for got, want in zip(g_ker, jax.grad(loss(chunked),
+                                         argnums=range(6))(*args)):
+        close(got, want)
+    if h0_scale:
+        return
+
+    def ref(dt, Bc, Cc, x, A):
+        return jnp.stack([JAMBA.recurrence(dt[b], Bc[b], Cc[b], x[b], A)
+                          for b in range(B)])
+
+    close(kernels(*args)[0], ref(*args[:5]))
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * dy),
+                     argnums=range(5))(*args[:5])
+    g_ker = jax.grad(lambda *a: jnp.sum(kernels(*a, h0)[0] * dy),
+                     argnums=range(5))(*args[:5])
+    for got, want in zip(g_ker, g_ref):
+        close(got, want)

@@ -12,6 +12,8 @@ except ImportError:  # bare container — CI installs the real thing
     from _hypothesis_fallback import given, settings, st
 
 from repro.configs import get_config, reduce_config
+from repro.core import hetero
+from repro.kernels.selective_scan import ops as ss_ops
 from repro.models import rwkv as rwkv_mod, ssm
 
 KEY = jax.random.PRNGKey(2)
@@ -67,6 +69,32 @@ def test_mamba_block_decode_continuation():
     y_inc = jnp.concatenate(ys, 1)
     np.testing.assert_allclose(np.asarray(y_inc), np.asarray(y_full),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_mamba_block_tally_is_the_same_on_either_scan_path(grad):
+    """One Mamba block over two kernel time blocks: unsharded it stages the
+    fused kernels, with an (identity) sharder the chunked scan alone; the
+    ``hetero`` tally reads the same either way, forward and gradient."""
+    cfg = reduce_config(get_config("jamba2-3b"))
+    p = ssm.init_mamba(cfg, KEY, jnp.float32)
+    x = jax.random.normal(KEY, (1, 2 * ss_ops.BLOCK_T, cfg.d_model))
+
+    def traced(sharder):
+        def f(x):
+            return jnp.sum(ssm.apply_mamba_block(cfg, p, x,
+                                                 sharder=sharder)[0])
+        fn = jax.grad(f) if grad else f
+        with hetero.tally() as counts:
+            text = str(jax.make_jaxpr(fn)(x))
+        return counts, text
+
+    fused, fused_text = traced(None)
+    chunked, chunked_text = traced(lambda a, name: a)
+    assert "selective_scan_fwd" in fused_text
+    assert "selective_scan_bwd" in fused_text or not grad
+    assert "pallas_call" not in chunked_text
+    assert fused == chunked and fused[hetero.DYNAMIC] > 0
 
 
 @pytest.mark.parametrize("chunk", [8, 32, 128])

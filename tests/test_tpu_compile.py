@@ -25,12 +25,15 @@ from repro.core.quant import quantize
 from repro.kernels.crossbar_matmul import ops as cb_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.rwkv6_wkv import ops as wkv_ops
+from repro.kernels.selective_scan import ops as ss_ops
 from repro.models import attention as attn
+from repro.models import ssm
 from repro.models import transformer as tfm
 from repro.optim import adamw
 from repro.train.steps import TrainHParams, make_train_step
 
 FUSED_KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+SCAN_KERNELS = ("selective_scan_fwd", "selective_scan_bwd")
 
 
 @pytest.fixture(scope="module")
@@ -160,12 +163,10 @@ def test_fused_attention_kernels_sit_under_attn_scope(one_chip):
         assert _innermost_layer(op_name) == obs.ATTN, (name, op_name)
 
 
-def test_jamba_train_step_keeps_its_layer_scopes(one_chip):
-    """One period of jamba2-3b at small width, remat on, compiled for the
-    v5e: with one KV head the attention's score and value products keep
-    their op_name under ``attn`` (the CPU compiler drops it), every matmul
-    lies under one layer scope, and the selective scan shows under
-    ``ssm_scan`` inside ``recurrent``."""
+@pytest.fixture(scope="module")
+def jamba_step_text(one_chip):
+    """One period of jamba2-3b at small width, remat on, one row of 256
+    tokens, its training step compiled for the v5e."""
     cfg = dataclasses.replace(reduce_config(get_config("jamba2-3b")),
                               n_layers=14)
     assert cfg.n_kv_heads == 1
@@ -176,8 +177,17 @@ def test_jamba_train_step_keeps_its_layer_scopes(one_chip):
     batch = {"tokens": jax.ShapeDtypeStruct((B, T), jnp.int32),
              "labels": jax.ShapeDtypeStruct((B, T), jnp.int32)}
     step = make_train_step(cfg, tfm.ExecConfig(remat=True), TrainHParams())
-    txt = _compile_text(step, (params, lora, jax.eval_shape(adamw.init, lora),
-                               batch, key), one_chip)
+    return _compile_text(step, (params, lora,
+                                jax.eval_shape(adamw.init, lora), batch, key),
+                         one_chip)
+
+
+def test_jamba_train_step_keeps_its_layer_scopes(jamba_step_text):
+    """With one KV head the attention's score and value products keep
+    their op_name under ``attn`` (the CPU compiler drops it), every matmul
+    lies under one layer scope, and the selective scan shows under
+    ``ssm_scan`` inside ``recurrent``."""
+    txt = jamba_step_text
     dots, scan = set(), 0
     for line in txt.splitlines():
         op = re.search(r'op_name="([^"]*)"', line)
@@ -193,6 +203,56 @@ def test_jamba_train_step_keeps_its_layer_scopes(one_chip):
     assert {obs.ATTN, obs.RECURRENT, obs.MLP, obs.HEAD} <= {
         layer for layer, _ in dots}
     assert scan > 0
+
+
+def test_selective_scan_kernels_sit_under_ssm_scan_scope(jamba_step_text):
+    """The jamba step's scans (T = 256, unsharded) run as the fused kernels,
+    forward and backward, each under the ``ssm_scan`` layer scope, so the
+    device trace counts them with the scan, and no conditional is left
+    from the platform choice."""
+    calls = _kernel_calls(jamba_step_text)
+    scans = {n: op for n, op in calls.items()
+             if n.split(".")[0] in SCAN_KERNELS}
+    assert {n.split(".")[0] for n in scans} == set(SCAN_KERNELS)
+    for name, op_name in scans.items():
+        assert _innermost_layer(op_name) == obs.SSM_SCAN, (name, op_name)
+    assert " conditional(" not in jamba_step_text
+
+
+def test_selective_scan_kernels_compile_jamba2_3b(one_chip):
+    """jamba2-3b's widths, one row of 8192 tokens: d_in 5120, d_state 16.
+    Forward and backward compile within VMEM, each kernel by name."""
+    B, T, D, N = 1, 8192, 5120, 16
+    seq = jax.ShapeDtypeStruct((B, T, D), jnp.float32)
+    bc = jax.ShapeDtypeStruct((B, T, N), jnp.float32)
+    A = jax.ShapeDtypeStruct((D, N), jnp.float32)
+    h = jax.ShapeDtypeStruct((B, D, N), jnp.float32)
+    hs = jax.ShapeDtypeStruct(ss_ops.block_states_shape((B, T, D), N),
+                              jnp.float32)
+    fwd = _compile_text(ss_ops.scan_fwd, (seq, bc, bc, seq, A, h), one_chip)
+    bwd = _compile_text(ss_ops.scan_bwd, (seq, bc, bc, seq, A, hs, seq, h),
+                        one_chip)
+    assert {n.split(".")[0] for n in _kernel_calls(fwd)} == {SCAN_KERNELS[0]}
+    assert {n.split(".")[0] for n in _kernel_calls(bwd)} == {SCAN_KERNELS[1]}
+
+
+@pytest.mark.parametrize("T", [1, 16])
+def test_decode_length_scan_keeps_the_xla_path(one_chip, T):
+    """A decode step or a short chunk of the Mamba block, with its cache,
+    at jamba2-3b's widths: the chunked scan in XLA, no kernel."""
+    cfg = get_config("jamba2-3b")
+    d_in, N = cfg.mamba.expand * cfg.d_model, cfg.mamba.d_state
+    assert not ss_ops.applies(T)
+    key = jax.random.PRNGKey(0)
+    p = jax.eval_shape(lambda k: ssm.init_mamba(cfg, k, jnp.bfloat16), key)
+    cache = {"conv": jax.ShapeDtypeStruct((2, cfg.mamba.d_conv - 1, d_in),
+                                          jnp.bfloat16),
+             "ssm": jax.ShapeDtypeStruct((2, d_in, N), jnp.float32)}
+    x = jax.ShapeDtypeStruct((2, T, cfg.d_model), jnp.bfloat16)
+    txt = _compile_text(
+        lambda p, x, c: ssm.apply_mamba_block(cfg, p, x, cache=c),
+        (p, x, cache), one_chip)
+    assert not _kernel_calls(txt)
 
 
 def test_rwkv6_wkv_compiles_rwkv6_7b(one_chip):
